@@ -55,7 +55,7 @@ def run_config(op: str, n_levels: int, capacity: int, *, n: int = 8192,
     vals = jnp.asarray(np.random.default_rng(0).standard_normal(n)
                        .astype(np.float32))
 
-    interpret = True if backend == "pallas" else None
+    interpret = backend == "pallas"  # a CPU bench: Pallas interpreted
 
     def once():
         return dataplane.run_cascade(keys, vals, plan, backend=backend,

@@ -56,7 +56,7 @@ def _stream(n: int, variety: int, dist: str, seed: int = 0):
     return keys, vals
 
 
-def _fpe_call(backend: str, interpret: bool | None):
+def _fpe_call(backend: str, interpret: bool):
     """One (keys, values, op, geometry, exact_stream) -> FPE 4-tuple."""
     if backend == "pallas":
         from repro.kernels.kv_aggregate import fpe_aggregate_pallas
@@ -125,7 +125,7 @@ def _conservation_check(call, keys, vals, *, op, capacity, ways,
 
 def run_config(op: str, *, n: int = 100_000, variety: int = 4096,
                capacity: int = 1024, ways: int = 4, dist: str = "zipf",
-               backend: str = "jnp", interpret: bool | None = None,
+               backend: str = "jnp", interpret: bool = False,
                reps: int = 3, scan_reps: int = 1,
                check: bool = False) -> dict:
     """One cell: time the scan oracle and the fast path on one stream."""
@@ -160,7 +160,7 @@ def run_config(op: str, *, n: int = 100_000, variety: int = 4096,
 
 
 def sweep(*, ops, lengths, ways_list, backends, variety: int,
-          capacity: int, dist: str, reps: int, interpret: bool | None = None,
+          capacity: int, dist: str, reps: int, interpret: bool = False,
           check: bool = False) -> list[dict]:
     rows = []
     for backend in backends:

@@ -89,6 +89,9 @@ def main() -> None:
     ap.add_argument("--out-dir",
                     default=os.path.join(os.path.dirname(__file__), "out"))
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke or args.ci:
         run_smoke(args.out_dir, ci=args.ci)
         return

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs.reduced import reduced_config
 from repro.data.pipeline import DataConfig, SyntheticLMData
+from repro.launch.mesh import make_mesh
 from repro.models.model import LMModel
 from repro.models import transformer as tfm
 from repro.optim import AdamWConfig, adamw_init, make_lr_schedule
@@ -28,7 +29,7 @@ def main():
     print(f"model: {cfg.name} | {cfg.param_count()/1e6:.2f}M params | "
           f"pattern {[s.mixer for s in cfg.pattern]}")
 
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     prof = TrainProfile(q_chunk=16, k_chunk=16, moe_token_chunk=64, remat="none")
     data = SyntheticLMData(cfg, DataConfig(seq_len=32, global_batch=8))
     opt_cfg = AdamWConfig()

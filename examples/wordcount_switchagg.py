@@ -26,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import collectives as coll
 from repro.core import dataplane, planner, reduction_model as rm, tree as tree_lib
+from repro.launch.mesh import make_mesh
 from repro.net import sim as netsim
 
 
@@ -33,7 +34,7 @@ def main():
     n_workers = 8
     pairs_per_worker = 4096
     key_variety = 2048
-    mesh = jax.make_mesh((4, 2, 1), ("data", "pod", "model"))
+    mesh = make_mesh((4, 2, 1), ("data", "pod", "model"))
 
     # --- the controller configures the job (paper §3/§4.1 protocol) -------
     tree = tree_lib.from_mesh(mesh, reduce_axes=("data", "pod"))

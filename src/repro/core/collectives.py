@@ -36,46 +36,6 @@ from . import dataplane
 from . import kvagg
 
 
-def axis_size_compat(axis_name: str) -> int:
-    """Static size of a bound mesh axis across jax versions.
-
-    ``jax.lax.axis_size`` is recent; older releases expose the bound axis
-    environment through ``jax.core.axis_frame``.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax import core as _core
-
-    # axis_frame returns the size directly on some releases, a frame with
-    # a .size attribute on others
-    frame = _core.axis_frame(axis_name)
-    return int(getattr(frame, "size", frame))
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, axis_names=None,
-                     check_vma=False):
-    """`jax.shard_map` across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` with ``axis_names``/``check_vma``;
-    older releases only have ``jax.experimental.shard_map.shard_map`` where
-    the manual/auto split is expressed through the ``auto`` frozenset and
-    replication checking through ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(fn, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, auto=auto)
-
-
 class GradAggMode(str, enum.Enum):
     GATHER = "gather"  # parameter-server: raw flows to the reducer (paper's no-agg baseline)
     FLAT = "flat"  # one flat all-reduce over every chip (single-switch / DAIET-like)
@@ -102,7 +62,7 @@ def tree_allreduce(x: jnp.ndarray, leaf_axis: str, upper_axes: tuple[str, ...]) 
     """
     flat = x.reshape(-1)
     n = flat.shape[0]
-    fanin = axis_size_compat(leaf_axis)
+    fanin = jax.lax.axis_size(leaf_axis)
     pad = (-n) % fanin
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
@@ -160,7 +120,7 @@ def tree_compress_allreduce(
             f"{len(upper_axes)} upper axis hop(s)")
     flat = x.reshape(-1)
     n = flat.shape[0]
-    fanin = axis_size_compat(leaf_axis)
+    fanin = jax.lax.axis_size(leaf_axis)
     pad = (-n) % fanin
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
@@ -227,7 +187,7 @@ def exchange_in_shardmap(
         res_leaves = treedef.flatten_up_to(residuals)
         new_res = []
         for g, r in zip(leaves, res_leaves):
-            k = max(1, int(g.size / axis_size_compat(leaf_axis) * k_fraction))
+            k = max(1, int(g.size / jax.lax.axis_size(leaf_axis) * k_fraction))
             o, nr = tree_compress_allreduce(
                 g, r, leaf_axis, upper_axes, k=k, fpe_capacity=fpe_capacity,
                 cascade=cascade,
@@ -390,7 +350,7 @@ def make_kv_tree_aggregator(
         plan=plan,
     )
     spec = P(level_axes)
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec, spec),

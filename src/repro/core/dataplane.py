@@ -191,7 +191,7 @@ def run_level(
     *,
     backend: str = "jnp",
     block_n: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
     exact_stream: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray, LevelStats]:
     """One cascade hop on carried values; traceable inside jit/shard_map.
@@ -264,7 +264,7 @@ def run_cascade(
     *,
     backend: str = "jnp",
     block_n: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
     final_combine: bool = True,
     prepare: bool = True,
     finalize: bool = True,
@@ -668,7 +668,7 @@ def simulate_plan(
     dist: str = "uniform",
     seed: int = 0,
     backend: str = "jnp",
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> dict:
     """Run a synthetic stream through the cascade and report per-level
     predicted (Eq. 3) vs simulated reduction — the dry-run's dataplane

@@ -244,15 +244,22 @@ def _fpe_batched(
     path's input-aligned [n]; slots hold EMPTY_KEY where nothing was
     evicted.  Callers treat both as masked streams, but the *pattern* is
     not the paper's per-arrival trace — use ``exact_stream=True`` for
-    that.  Requires ``n * max(n_buckets, capacity) < 2**31`` (int32
-    composites); larger calls fall back to the exact scan.
+    that.  Requires ``n * max(n_buckets, capacity) < 2**31 - 1`` (int32
+    composites) and raises ``ValueError`` beyond it: callers chunk longer
+    streams, as ``dataplane.LevelState`` does with ``batch_pad``.
     """
     aggop = aggops.get(op)
     combine = aggop.combine  # resolved once, outside all vector math
     n = keys.shape[0]
     ways, n_buckets, cap = _fpe_geometry(capacity, ways)
     imax = jnp.iinfo(jnp.int32).max
-    if n == 0 or n * max(n_buckets, cap) >= imax:
+    if n * max(n_buckets, cap) >= imax:
+        raise ValueError(
+            f"FPE fast path: n * max(n_buckets, capacity) = "
+            f"{n * max(n_buckets, cap)} is not below the int32 limit "
+            f"2**31 - 1 (n={n}, capacity={cap}); chunk the stream into "
+            f"calls of at most {(imax - 1) // max(n_buckets, cap)} records")
+    if n == 0:
         res = _fpe_scan(keys, values, capacity=capacity, ways=ways, op=op,
                         table_keys=table_keys, table_values=table_values)
         pad_ev = jnp.full((cap,), EMPTY_KEY, jnp.int32)

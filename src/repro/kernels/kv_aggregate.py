@@ -2,24 +2,25 @@
 
 TPU adaptation of the paper's front-end processing engine (§4.2.4):
 
-  * The hash table lives in **VMEM** (the switch's SRAM analogue): keys
-    ``[n_buckets, ways]`` int32 + values ``[n_buckets, ways, lanes]``,
-    allocated as Pallas scratch so it persists across grid steps while the
-    input stream is tiled through HBM->VMEM block by block (BlockSpec
-    pipeline = the paper's line-rate packet flow).
-  * ``ways`` is the **lane dimension** of the bucket probe: one probe is a
-    single VPU compare over the (1, ways) row — the hardware's parallel
-    slot compare.  Use ways=128 on real TPUs for full-lane utilization;
-    tests sweep small widths in interpret mode.
+  * The hash table lives in **VMEM** (the switch's SRAM analogue) as the
+    kernel's resident output blocks, so it persists across grid steps while
+    the input stream is tiled through block by block (the BlockSpec
+    pipeline is the paper's line-rate packet flow).
+  * The table is **lane-dense**: slot ``b * stride + w`` (bucket ``b``,
+    way ``w``, ``stride`` = ``ways`` rounded up to a power of two) lives in
+    a stack of ``(8, 128)`` tiles, one 32-bit vreg each.  A probe loads the
+    one tile holding the bucket, compares its ways with masked VPU ops,
+    and stores the tile back: no narrow minor dimension pads the table to
+    128 lanes, so a 65,536-pair table takes 256 KiB of VMEM per lane.
+  * Keys and values stream through **SMEM**: each record is a scalar read
+    at a dynamic index, which a VMEM vector block cannot serve.  The
+    eviction stream leaves through SMEM blocks the same way.
   * On collision the resident way-0 pair is **evicted to the output stream**
     (never a stall/retry — the paper's no-penalty miss), the row shifts
-    left, and the new pair occupies the last way (LRU-ish, as in the paper
-    where the previously stored key is replaced).
-  * The eviction stream (the BPE feed) leaves through a second output, one
-    slot per input element, EMPTY_KEY where nothing was evicted.  The BPE
-    combine itself is a bulk sort+segment-sum on the eviction stream
-    (``ops.two_level_aggregate``) whose latency overlaps the next FPE block
-    exactly as the paper overlaps DRAM latency.
+    left, and the new pair occupies the last way.  The eviction stream
+    (the BPE feed) has one slot per input element, EMPTY_KEY where nothing
+    was evicted; the BPE combine is a bulk sort+segment-sum on it
+    (``kvagg.assemble_node``).
 
 Semantics are bit-identical to ``repro.core.kvagg.fpe_aggregate`` (the
 pure-jnp oracle re-exported via ``ref.py``).
@@ -28,10 +29,8 @@ Op semantics come from the ``core.aggops`` registry (DESIGN.md §6): the
 ``op`` string is resolved to its ``combine`` ONCE at trace time, before the
 kernel body is built, so each compiled kernel stays specialized to one op.
 Multi-lane carried ops (``mean``'s paired (sum, count) lanes) run in the
-SAME single ``pallas_call``: the value stream is ``[block_n, lanes]`` and
-the VMEM table carries a trailing lane dimension — eviction decisions are
-key-driven, so all lanes ride one probe/update per element instead of the
-one-kernel-launch-per-lane wrapper this replaced (DESIGN.md §8).
+SAME single ``pallas_call``: every combine is elementwise per lane, so the
+table keeps one tile stack per lane and each probe updates all of them.
 
 ``exact_stream=False`` runs the batched-block fast path (DESIGN.md §8):
 the block is pre-combined to distinct keys by the jnp ``sorted_combine``
@@ -54,93 +53,96 @@ from repro.core import kvagg as _kvagg
 
 EMPTY_KEY = -1  # plain int so kernels inline it as a literal
 
+# One table tile is one 32-bit vreg: (sublanes, lanes).
+_SUB, _LANE = 8, 128
+_TILE = _SUB * _LANE
+
 # THE key hash (core.aggops.hash_key): one copy shared with the jnp engine
 # so the kernel's bucket function can never drift from the oracle's.
 _hash = aggops.hash_key
 
 
+def _table_geometry(capacity: int, ways: int) -> tuple[int, int, int, int]:
+    """(ways, n_buckets, stride, n_tiles) of the lane-dense VMEM table.
+
+    A bucket occupies ``stride`` consecutive lanes of one tile row, so its
+    ways never straddle a row and a one-lane roll shifts it left."""
+    ways, n_buckets, _ = _kvagg._fpe_geometry(capacity, ways)
+    stride = 1 << (ways - 1).bit_length()
+    if stride > _LANE:
+        raise ValueError(f"ways={ways} exceeds one {_LANE}-lane tile row")
+    return ways, n_buckets, stride, -(-n_buckets * stride // _TILE)
+
+
 def _fpe_kernel(
-    keys_ref,  # [block_n] int32 (VMEM, streamed)
-    vals_ref,  # [block_n, lanes] (VMEM, streamed)
-    evk_ref,  # [block_n] int32 out — eviction stream block
-    evv_ref,  # [block_n, lanes] out
-    otk_ref,  # [n_buckets, ways] int32 out — final table (written at flush)
-    otv_ref,  # [n_buckets, ways, lanes] out
-    tk_ref,  # scratch: resident keys
-    tv_ref,  # scratch: resident values (lane dim trailing)
+    keys_ref,  # [1, block_n] int32 (SMEM, streamed)
+    vals_ref,  # [lanes, 1, block_n] (SMEM, streamed)
+    evk_ref,  # [1, block_n] int32 out (SMEM) — eviction stream block
+    evv_ref,  # [lanes, 1, block_n] out (SMEM)
+    tk_ref,  # [n_tiles, 8, 128] int32 out (VMEM, resident) — table keys
+    tv_ref,  # [lanes, n_tiles, 8, 128] out (VMEM, resident) — table values
     *,
     n_buckets: int,
     ways: int,
+    stride: int,
     lanes: int,
     combine,  # aggops combine fn, resolved ONCE before the body is traced
-    n_blocks: int,
 ):
-    pid = pl.program_id(0)
-
-    @pl.when(pid == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        tk_ref[...] = jnp.full((n_buckets, ways), EMPTY_KEY, dtype=jnp.int32)
-        tv_ref[...] = jnp.zeros((n_buckets, ways, lanes), dtype=tv_ref.dtype)
+        tk_ref[...] = jnp.full(tk_ref.shape, EMPTY_KEY, jnp.int32)
+        tv_ref[...] = jnp.zeros(tv_ref.shape, tv_ref.dtype)
 
-    block_n = keys_ref.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, ways), 1)
+    vdt = tv_ref.dtype
+    # flat slot of every element of a tile, row-major over (8, 128)
+    slot = (jax.lax.broadcasted_iota(jnp.int32, (_SUB, _LANE), 0) * _LANE
+            + jax.lax.broadcasted_iota(jnp.int32, (_SUB, _LANE), 1))
 
-    def body(i, _):
-        k = keys_ref[i]
-        v = vals_ref[i, :]  # [lanes] — every value lane of this element
+    def shift_left(x):  # element j takes element j + 1 of its row
+        return pltpu.roll(x, _LANE - 1, 1)
+
+    def pick(mask, x):  # the one element of x under mask, as a scalar
+        return jnp.sum(jnp.where(mask, x, jnp.zeros((), x.dtype)))
+
+    def body(i, carry):
+        k = keys_ref[0, i]
         is_pad = k == EMPTY_KEY
-        b = _hash(k, n_buckets)
+        base = _hash(k, n_buckets) * stride
+        t = base // _TILE
+        # a padding record addresses no slot, so every mask below is empty
+        w = slot - jnp.where(is_pad, 2 * _TILE, base % _TILE)  # way index
+        in_b = (w >= 0) & (w < ways)
 
-        row_k = pl.load(tk_ref, (pl.ds(b, 1), slice(None)))  # (1, ways)
-        row_v = pl.load(
-            tv_ref, (pl.ds(b, 1), slice(None), slice(None)))  # (1, ways, L)
+        row_k = tk_ref[t]
+        hit = in_b & (row_k == k)  # the bucket probe: one VPU compare
+        any_hit = jnp.max(hit.astype(jnp.int32)) > 0
+        empty_idx = jnp.min(jnp.where(in_b & (row_k == EMPTY_KEY), w, ways))
+        any_empty = empty_idx < ways
+        evicted = ~any_hit & ~any_empty & ~is_pad
+        # miss+empty: insert at the first empty way; miss+full: evict way 0,
+        # shift the bucket left and insert at the last way
+        ins = in_b & (w == jnp.where(any_hit, -1,
+                                     jnp.where(any_empty, empty_idx,
+                                               ways - 1)))
+        shifted = in_b & (w < jnp.where(evicted, ways - 1, 0))
+        way0 = in_b & (w == 0)
 
-        hit = row_k == k  # (1, ways) — one VPU compare = the bucket probe
-        any_hit = jnp.any(hit) & ~is_pad
-        empty = row_k == EMPTY_KEY
-        any_empty = jnp.any(empty) & ~is_pad
-        empty_idx = jnp.argmax(empty.astype(jnp.int32))  # first empty way
+        ev_k = pick(way0, row_k)
+        tk_ref[t] = jnp.where(ins, k, jnp.where(shifted, shift_left(row_k),
+                                                row_k))
+        evk_ref[0, i] = jnp.where(evicted, ev_k, EMPTY_KEY)
+        for l in range(lanes):
+            v = vals_ref[l, 0, i]
+            row_v = tv_ref[l, t]
+            ev_v = pick(way0, row_v)
+            tv_ref[l, t] = jnp.where(
+                hit, combine(row_v, v),
+                jnp.where(ins, v, jnp.where(shifted, shift_left(row_v),
+                                            row_v)))
+            evv_ref[l, 0, i] = jnp.where(evicted, ev_v, jnp.zeros((), vdt))
+        return carry
 
-        v_row = v[None, None, :]  # (1, 1, lanes) — broadcasts over ways
-
-        # hit: aggregate every lane into the matching way
-        agg_v = jnp.where(hit[..., None], combine(row_v, v_row), row_v)
-
-        # miss+empty: insert at first empty way
-        at_empty = lane == empty_idx
-        ins_k = jnp.where(at_empty, k, row_k)
-        ins_v = jnp.where(at_empty[..., None], v_row, row_v)
-
-        # miss+full: evict way 0, shift left, insert at last way
-        ev_k = row_k[0, 0]
-        ev_v = row_v[0, 0, :]  # [lanes]
-        at_last = lane == ways - 1
-        sh_k = jnp.where(at_last, k, jnp.roll(row_k, -1, axis=1))
-        sh_v = jnp.where(at_last[..., None], v_row,
-                         jnp.roll(row_v, -1, axis=1))
-
-        new_k = jnp.where(any_hit, row_k, jnp.where(any_empty, ins_k, sh_k))
-        new_v = jnp.where(any_hit, agg_v, jnp.where(any_empty, ins_v, sh_v))
-        new_k = jnp.where(is_pad, row_k, new_k)
-        new_v = jnp.where(is_pad, row_v, new_v)
-
-        evicted = (~any_hit) & (~any_empty) & (~is_pad)
-        out_k = jnp.where(evicted, ev_k, EMPTY_KEY)
-        out_v = jnp.where(evicted, ev_v, jnp.zeros((lanes,), tv_ref.dtype))
-
-        pl.store(tk_ref, (pl.ds(b, 1), slice(None)), new_k)
-        pl.store(tv_ref, (pl.ds(b, 1), slice(None), slice(None)), new_v)
-        pl.store(evk_ref, (pl.ds(i, 1),), out_k[None])
-        pl.store(evv_ref, (pl.ds(i, 1), slice(None)), out_v[None])
-        return 0
-
-    jax.lax.fori_loop(0, block_n, body, 0)
-
-    # End-of-task flush (paper's EoT): emit the resident table once.
-    @pl.when(pid == n_blocks - 1)
-    def _flush():
-        otk_ref[...] = tk_ref[...]
-        otv_ref[...] = tv_ref[...]
+    jax.lax.fori_loop(0, keys_ref.shape[1], body, 0)
 
 
 @functools.partial(
@@ -157,7 +159,7 @@ def fpe_aggregate_pallas(
     op: str = "sum",
     block_n: int = 512,
     exact_stream: bool = True,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Run the FPE kernel over a KV stream.
 
@@ -165,8 +167,11 @@ def fpe_aggregate_pallas(
              evict_keys [n], evict_values [n, *lanes]) — same contract as
     ``core.kvagg.fpe_aggregate``.  Values with a trailing lane dim (multi-
     lane carried ops, e.g. ``mean``) run in the SAME kernel launch: the
-    VMEM table carries a lane dimension and each element's probe updates
-    every lane at once.
+    VMEM table keeps one tile stack per lane and each element's probe
+    updates every lane at once.
+
+    ``interpret=True`` runs the kernel in the Pallas interpreter (the CPU
+    tests); the default compiles it for the TPU.
 
     ``exact_stream=False`` pre-combines the block to distinct keys
     (``kvagg.sorted_combine`` — vectorized) before streaming it through
@@ -178,59 +183,66 @@ def fpe_aggregate_pallas(
     (displaced residents appended) — compare the two fast modes by
     resident table and grouped totals, not elementwise stream shape.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n = keys.shape[0]
     squeeze = values.ndim == 1
     if exact_stream is False:
         c = _kvagg.sorted_combine(keys, values, op=op)
         keys, values = c.unique_keys, c.combined_values
-    if values.ndim == 1:
-        values = values[:, None]
-    lanes = values.shape[1]
-    ways, n_buckets, cap = _kvagg._fpe_geometry(capacity, ways)
+    # lane-major [lanes, n]: an [n, lanes] array with a narrow minor dim
+    # pads to 128 lanes in HBM
+    values = values[None] if squeeze else values.T
+    lanes = values.shape[0]
+    ways, n_buckets, stride, n_tiles = _table_geometry(capacity, ways)
 
     pad = (-n) % block_n
     if pad:
         keys = jnp.concatenate([keys, jnp.full((pad,), EMPTY_KEY, jnp.int32)])
         values = jnp.concatenate(
-            [values, jnp.zeros((pad, lanes), values.dtype)])
+            [values, jnp.zeros((lanes, pad), values.dtype)], axis=1)
     total = keys.shape[0]
-    n_blocks = total // block_n
 
     kernel = functools.partial(
-        _fpe_kernel, n_buckets=n_buckets, ways=ways, lanes=lanes,
-        combine=aggops.get(op).combine, n_blocks=n_blocks,
-    )
-    out_shapes = (
-        jax.ShapeDtypeStruct((total,), jnp.int32),  # evict keys
-        jax.ShapeDtypeStruct((total, lanes), values.dtype),  # evict values
-        jax.ShapeDtypeStruct((n_buckets, ways), jnp.int32),  # table keys
-        jax.ShapeDtypeStruct((n_buckets, ways, lanes), values.dtype),
-    )
-    grid = (n_blocks,)
+        _fpe_kernel, n_buckets=n_buckets, ways=ways, stride=stride,
+        lanes=lanes, combine=aggops.get(op).combine)
+    n_blocks = total // block_n
+
+    def stream(lead):  # block i of a [*lead, n_blocks, 1, block_n] stream
+        return pl.BlockSpec((*lead, None, 1, block_n),
+                            lambda i: (*(0 for _ in lead), i, 0, 0),
+                            memory_space=pltpu.SMEM)
+
     evk, evv, otk, otv = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n, lanes), lambda i: (i, 0)),
-        ],
+        grid=(n_blocks,),
+        in_specs=[stream(()), stream((lanes,))],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n, lanes), lambda i: (i, 0)),
-            pl.BlockSpec((n_buckets, ways), lambda i: (0, 0)),
-            pl.BlockSpec((n_buckets, ways, lanes), lambda i: (0, 0, 0)),
+            stream(()),
+            stream((lanes,)),
+            pl.BlockSpec((n_tiles, _SUB, _LANE), lambda i: (0, 0, 0)),
+            pl.BlockSpec((lanes, n_tiles, _SUB, _LANE),
+                         lambda i: (0, 0, 0, 0)),
         ],
-        out_shape=out_shapes,
-        scratch_shapes=[
-            pltpu.VMEM((n_buckets, ways), jnp.int32),
-            pltpu.VMEM((n_buckets, ways, lanes), values.dtype),
-        ],
+        out_shape=(
+            jax.ShapeDtypeStruct((n_blocks, 1, block_n), jnp.int32),
+            jax.ShapeDtypeStruct((lanes, n_blocks, 1, block_n),
+                                 values.dtype),
+            jax.ShapeDtypeStruct((n_tiles, _SUB, _LANE), jnp.int32),
+            jax.ShapeDtypeStruct((lanes, n_tiles, _SUB, _LANE),
+                                 values.dtype),
+        ),
+        # the table carries from one block to the next: a sequential grid
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(keys, values)
-    tv = otv.reshape(cap, lanes)
-    ek, ev = evk[:n], evv[:n]
+    )(keys.reshape(n_blocks, 1, block_n),
+      values.reshape(lanes, n_blocks, 1, block_n))
+    # lane-dense tiles -> the oracle's flat [n_buckets * ways] slot order
+    tk = otk.reshape(-1)[:n_buckets * stride].reshape(n_buckets, stride)
+    tv = otv.reshape(lanes, -1)[:, :n_buckets * stride].reshape(
+        lanes, n_buckets, stride)
+    tk = tk[:, :ways].reshape(-1)
+    tv = tv[:, :, :ways].reshape(lanes, -1)
+    ek, ev = evk.reshape(total)[:n], evv.reshape(lanes, total)[:, :n]
     if squeeze:
-        return otk.reshape(cap), tv[:, 0], ek, ev[:, 0]
-    return otk.reshape(cap), tv, ek, ev
+        return tk, tv[0], ek, ev[0]
+    return tk, tv.T, ek, ev.T

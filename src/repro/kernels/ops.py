@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from repro.core import kvagg as _kvagg
 from .kv_aggregate import fpe_aggregate_pallas
-from .topk_compress import topk_rows_pallas
 
 EMPTY_KEY = _kvagg.EMPTY_KEY
 
@@ -50,7 +49,7 @@ def two_level_aggregate(
     block_n: int = 512,
     bpe: bool = True,
     exact_stream: bool = True,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> TwoLevelOut:
     """SwitchAgg node with the Pallas FPE (kernel) + BPE (bulk combine).
 
@@ -67,30 +66,3 @@ def two_level_aggregate(
     return TwoLevelOut(*_kvagg.assemble_node(keys, tk, tv, ek, ev,
                                              op=op, bpe=bpe))
 
-
-@functools.partial(jax.jit, static_argnames=("k", "chunk", "block_rows", "interpret"))
-def compress_grad(
-    grad: jnp.ndarray,
-    residual: jnp.ndarray,
-    *,
-    k: int,
-    chunk: int = 4096,
-    block_rows: int = 8,
-    interpret: bool | None = None,
-):
-    """Blockwise top-k gradient -> KV payload using the Pallas kernel.
-
-    Returns (keys [rows*k] int32 global flat indices, values [rows*k],
-    new_residual) with error feedback.  ``chunk`` is the per-FPE-group
-    working set (cols per row).
-    """
-    acc = grad.astype(residual.dtype).reshape(-1) + residual.reshape(-1)
-    n = acc.shape[0]
-    if n % chunk != 0:
-        raise ValueError(f"grad size {n} not divisible by chunk {chunk}")
-    mat = acc.reshape(-1, chunk)
-    vals, idx = topk_rows_pallas(mat, k=k, block_rows=block_rows, interpret=interpret)
-    rows = mat.shape[0]
-    gkeys = (idx + jnp.arange(rows, dtype=jnp.int32)[:, None] * chunk).reshape(-1)
-    new_res = acc.at[gkeys].set(0.0).reshape(residual.shape)
-    return gkeys, vals.reshape(-1), new_res

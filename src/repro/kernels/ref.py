@@ -42,26 +42,5 @@ def sorted_combine_ref(keys, values, *, op: str = "sum"):
     return _kvagg.sorted_combine(keys, values, op=op)
 
 
-def topk_ref(x: jnp.ndarray, k: int):
-    """Oracle for the per-row magnitude top-k kernel.
-
-    x: [rows, cols] -> (values [rows,k], indices [rows,k]) where values are
-    the originals (signed) at the k largest-|.| positions, ordered by
-    descending magnitude; ties broken by lower index (matches the kernel's
-    iterative argmax).
-    """
-    rows = x.shape[0]
-    mag = jnp.abs(x.astype(jnp.float32))
-
-    def step(m, _):
-        am = jnp.argmax(m, axis=-1)  # first max on ties, like the kernel
-        v = jnp.take_along_axis(x, am[:, None], axis=-1)[:, 0]
-        m = m.at[jnp.arange(rows), am].set(-jnp.inf)
-        return m, (v, am.astype(jnp.int32))
-
-    _, (vs, ams) = jax.lax.scan(step, mag, None, length=k)
-    return vs.T, ams.T
-
-
 def segment_sum_ref(values: jnp.ndarray, segment_ids: jnp.ndarray, num_segments: int):
     return jax.ops.segment_sum(values, segment_ids, num_segments=num_segments)

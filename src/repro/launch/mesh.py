@@ -8,29 +8,24 @@ any jax initialization).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # older jax: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def _make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions (axis_types grew in later releases)."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """THE mesh constructor: every axis Auto-typed.
+
+    ``jax.make_mesh`` defaults to Explicit axes, under which the model's
+    ``with_sharding_constraint`` calls and gathers are refused; the train
+    step's shard_map regions expect the auto (SPMD) model axis."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh_compat(shape, axes)
-
-
-def make_mesh(shape, axes):
-    return _make_mesh_compat(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 # Hardware constants for the roofline (TPU v5e-class target).

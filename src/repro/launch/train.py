@@ -33,6 +33,8 @@ import repro.configs as configs
 from repro.configs.reduced import reduced_config
 from repro.core.collectives import GradAggMode
 from repro.data.pipeline import DataConfig, SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.model import LMModel
 from repro.optim import AdamWConfig, adamw_init, make_lr_schedule
 from repro.runtime.fault_tolerance import TrainLoop, TrainLoopConfig
@@ -50,7 +52,7 @@ def parse_mesh(spec: str | None):
         dims = (n, 1)
     names = {1: ("data",), 2: ("data", "model"), 3: ("pod", "data", "model")}[len(dims)]
     assert np.prod(dims) == n, f"mesh {dims} != devices {n}"
-    return jax.make_mesh(dims, names)
+    return make_mesh(dims, names)
 
 
 def build_config(args):
@@ -92,6 +94,7 @@ def main(argv=None):
     ap.add_argument("--k-chunk", type=int, default=128)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
 
@@ -150,10 +153,13 @@ def main(argv=None):
                               jnp.asarray(i, jnp.int32))
             return {"params": p, "opt": o}, m
 
+    def batch_fn(i):  # each batch lands split over the dp axes
+        return jax.device_put(data.batch_at(i), sh["batch"])
+
     loop = TrainLoop(
         TrainLoopConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
                         ckpt_every=args.ckpt_every, log_every=args.log_every),
-        loop_step, data.batch_at, state,
+        loop_step, batch_fn, state,
     )
     t0 = time.time()
     final = loop.run()

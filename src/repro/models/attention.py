@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MLAConfig, ModelConfig
-from repro.core.collectives import shard_map_compat
 from .layers import apply_rope, rms_norm, softcap
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -404,7 +403,7 @@ def decode_attn(
         )
         # only the manual (cache-seq) axes appear in specs; batch sharding
         # over the dp axes stays auto and flows through untouched
-        out = shard_map_compat(
+        out = jax.shard_map(
             fn,
             mesh=policy.mesh,
             in_specs=(
@@ -487,7 +486,7 @@ def mla_decode(
 
     axes = policy.cache_seq_axes
     if policy.distributed and axes:
-        o_c = shard_map_compat(
+        o_c = jax.shard_map(
             local_fn,
             mesh=policy.mesh,
             in_specs=(

@@ -135,10 +135,13 @@ def mamba_dense(x: jnp.ndarray, p: dict, cfg: ModelConfig) -> jnp.ndarray:
 
     # ---- intra-chunk (diagonal) + inter-chunk (off-diagonal) outputs
     cb = jnp.einsum("bcihn,bcjhn->bchij", cc, bc_, preferred_element_type=jnp.float32)
-    # decay matrix per head: exp(csum_i - csum_j), causal (i >= j)
-    dmat = jnp.exp(csum[:, :, :, None, :] - csum[:, :, None, :, :])  # [B,nc,Qi,Qj,H]
+    # decay matrix per head: exp(csum_i - csum_j), causal (i >= j).  Mask
+    # BEFORE the exp: above the diagonal csum_i - csum_j grows with the
+    # chunk and overflows, and the backward pass of a masked inf is NaN.
     mask = jnp.tril(jnp.ones((q, q), bool))
-    dmat = jnp.where(mask[None, None, :, :, None], dmat, 0.0)
+    dmat = jnp.exp(jnp.where(mask[None, None, :, :, None],
+                             csum[:, :, :, None, :] - csum[:, :, None, :, :],
+                             -jnp.inf))  # [B,nc,Qi,Qj,H]
     att = cb * jnp.moveaxis(dmat, -1, 2)  # [B,nc,H,Qi,Qj]
     att = att * dtc.transpose(0, 1, 3, 2)[:, :, :, None, :]  # dt_j on the j axis
     y_diag = jnp.einsum(

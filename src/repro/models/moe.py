@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.collectives import shard_map_compat
 from .attention import ShardingPolicy
 from .layers import activation, gated_mlp
 
@@ -218,7 +217,7 @@ def moe_apply(
                 y = fn(xf, gf, ef, wg, wu, wd)
             return y.reshape(xl.shape)
 
-        y = shard_map_compat(
+        y = jax.shard_map(
             region,
             mesh=policy.mesh,
             in_specs=(

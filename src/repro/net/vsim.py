@@ -120,11 +120,8 @@ def tier_ingest(keys, values, *, capacity: int, ways: int, op: str,
     nor its order.  ``n_evict`` (the pre-combine real-eviction count) lets
     the host verify the ``<= R`` invariant actually held.
     """
-    w, n_buckets, cap = kvagg._fpe_geometry(capacity, ways)
+    _, _, cap = kvagg._fpe_geometry(capacity, ways)
     lane_shape = values.shape[3:]
-    if exact_stream and values.dtype == jnp.float32:
-        return _tier_ingest_packed(keys, values, capacity=capacity,
-                                   ways=ways, op=op, bpe=bpe)
 
     def one_switch(ks, vs):
         def step(carry, pkt):
@@ -156,102 +153,6 @@ def tier_ingest(keys, values, *, capacity: int, ways: int, op: str,
         return tk, tv, ek, ev, ne, no
 
     return jax.vmap(one_switch)(keys, values)
-
-
-def _tier_ingest_packed(keys, values, *, capacity: int, ways: int, op: str,
-                        bpe: bool):
-    """``tier_ingest``'s exact-stream body with keys and value lanes
-    packed into ONE table array.
-
-    ``kvagg._fpe_scan``'s per-record step costs two gathers and two
-    scatters per record (separate key/value tables); under ``vmap`` those
-    batched gathers/scatters dominate the kernel on CPU.  Bitcasting keys
-    (int32 -> float32, ``lax.bitcast_convert_type``) into lane 0 of the
-    value table halves them.  The selection logic (hit / first-empty /
-    evict-shift) is replicated branch for branch, and no arithmetic ever
-    touches the bitcast key lane — every float is moved or combined by
-    exactly the expressions of the reference step, so tables and eviction
-    streams stay BIT-identical to ``kvagg.fpe_aggregate``.
-    """
-    aggop = kvagg.aggops.get(op)
-    w, n_buckets, cap = kvagg._fpe_geometry(capacity, ways)
-    lane_shape = values.shape[3:]
-    lane_nd = len(lane_shape)
-    lanes = 1
-    for d in lane_shape:
-        lanes *= d
-    rpp = keys.shape[2]
-    vals_flat = values.reshape(values.shape[:3] + (lanes,))
-    empty_f = jax.lax.bitcast_convert_type(kvagg.EMPTY_KEY, jnp.float32)
-
-    def one_switch(ks, vs):  # ks [P, R], vs [P, R, lanes]
-        def rec_step(tab, inp):  # tab [n_buckets, w, 1 + lanes]
-            k, v = inp  # k scalar int32, v [lanes] float32
-            b = kvagg.hash_key(k, n_buckets)
-            row = tab[b]  # [w, 1 + lanes] — ONE gather
-            row_k = jax.lax.bitcast_convert_type(row[:, 0], jnp.int32)
-            row_v = row[:, 1:].reshape((w,) + lane_shape)
-            v_l = v.reshape(lane_shape)
-            is_pad = k == kvagg.EMPTY_KEY
-
-            hit = row_k == k  # [w]
-            any_hit = jnp.any(hit) & ~is_pad
-            empty = row_k == kvagg.EMPTY_KEY
-            any_empty = jnp.any(empty) & ~is_pad
-            empty_idx = jnp.argmax(empty)  # first empty way
-            hit_l = hit.reshape(hit.shape + (1,) * lane_nd)
-
-            # --- hit: aggregate into the matching way (key lane kept)
-            agg_v = jnp.where(hit_l, aggop.combine(row_v, v_l), row_v)
-            agg_row = jnp.concatenate(
-                [row[:, :1], agg_v.reshape(w, lanes)], axis=1)
-
-            # packed (key, value) record for insert / shift-in
-            kv = jnp.concatenate(
-                [jax.lax.bitcast_convert_type(k, jnp.float32)[None], v])
-
-            # --- miss+empty: insert at first empty way
-            ins_row = row.at[empty_idx].set(kv)
-
-            # --- miss+full: evict way 0, shift left, insert at last way
-            ev_k, ev_v = row_k[0], row_v[0]
-            sh_row = jnp.concatenate([row[1:], kv[None]])
-
-            new_row = jnp.where(
-                any_hit, agg_row, jnp.where(any_empty, ins_row, sh_row))
-            evicted = (~any_hit) & (~any_empty) & (~is_pad)
-            out_k = jnp.where(evicted, ev_k, kvagg.EMPTY_KEY)
-            out_v = jnp.where(evicted, ev_v, jnp.zeros_like(ev_v))
-
-            new_row = jnp.where(is_pad, row, new_row)
-            tab = tab.at[b].set(new_row)  # ONE scatter
-            return tab, (out_k, out_v.reshape(lanes))
-
-        def pkt_step(tab, pkt):
-            pk, pv = pkt
-            # modest unroll trims scan-iteration overhead on CPU without
-            # the compile-time blowup of a full R-way unroll
-            tab, (ek, ev) = jax.lax.scan(rec_step, tab, (pk, pv),
-                                         unroll=min(4, rpp))
-            n_ev = jnp.sum(ek != kvagg.EMPTY_KEY).astype(jnp.int32)
-            if bpe:  # per-packet eviction combine, fixed shape
-                c = kvagg.sorted_combine(
-                    ek, ev.reshape((rpp,) + lane_shape), op=op)
-                ek = c.unique_keys
-                ev = c.combined_values.reshape(rpp, lanes)
-            n_out = jnp.sum(ek != kvagg.EMPTY_KEY).astype(jnp.int32)
-            return tab, (ek, ev, n_ev, n_out)
-
-        tab0 = jnp.concatenate(
-            [jnp.full((n_buckets, w, 1), empty_f, jnp.float32),
-             jnp.zeros((n_buckets, w, lanes), jnp.float32)], axis=2)
-        tab, (ek, ev, ne, no) = jax.lax.scan(pkt_step, tab0, (ks, vs))
-        tk = jax.lax.bitcast_convert_type(
-            tab[:, :, 0], jnp.int32).reshape(cap)
-        tv = tab[:, :, 1:].reshape((cap,) + lane_shape)
-        return tk, tv, ek, ev.reshape((ek.shape[0], rpp) + lane_shape), ne, no
-
-    return jax.vmap(one_switch)(keys, vals_flat)
 
 
 # --------------------------------------------------------------------------

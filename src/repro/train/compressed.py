@@ -26,7 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import collectives as coll
-from repro.core.collectives import GradAggMode, axis_size_compat
+from repro.core.collectives import GradAggMode
 from repro.models.attention import ShardingPolicy
 from repro.models.model import LMModel
 from repro.models.transformer import ApplyOptions
@@ -177,7 +177,7 @@ def build_compressed_train_step(
         # mean over workers
         w = 1.0
         for ax in prof.dp_axes:
-            w *= axis_size_compat(ax)
+            w *= jax.lax.axis_size(ax)
         grads = jax.tree.map(lambda g: g / w, grads)
         if wire_dtype is not None:
             grads = jax.tree.map(lambda g: g.astype(wire_dtype), grads)
@@ -198,7 +198,7 @@ def build_compressed_train_step(
 
         return jax.tree.map(one, b)
 
-    mapped = coll.shard_map_compat(
+    mapped = jax.shard_map(
         region,
         mesh=mesh,
         in_specs=(pspecs_region, batch_region_specs(batch_example),

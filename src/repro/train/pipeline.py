@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.collectives import shard_map_compat
 
 
 def gpipe_forward(
@@ -96,7 +95,7 @@ def make_gpipe_fn(mesh, stage_axis: str, n_stages: int, stage_fn: Callable):
         return gpipe_forward(x_micro, stage_fn, mine, axis=stage_axis,
                              n_stages=n_stages)
 
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         region,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),  # prefix spec for the params pytree

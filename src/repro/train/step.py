@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.collectives import GradAggMode, shard_map_compat
+from repro.core.collectives import GradAggMode
 from repro.models import sharding as shd
 from repro.models.attention import ShardingPolicy
 from repro.models.model import LMModel
@@ -191,7 +191,7 @@ def build_train_step(
                         stacked = jnp.mean(stacked, axis=0)
                     return stacked
 
-                return shard_map_compat(
+                return jax.shard_map(
                     body, mesh=mesh,
                     in_specs=P(), out_specs=P(),
                     axis_names=set(prof.dp_axes), check_vma=False,

@@ -15,12 +15,12 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import collectives as coll
-from repro.core.collectives import shard_map_compat
 from repro.core import kvagg
+from repro.launch.mesh import make_mesh
 
 assert jax.device_count() == 8, jax.device_count()
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 def check_tree_equals_flat():
@@ -36,7 +36,7 @@ def check_tree_equals_flat():
             return coll.tree_allreduce(xl, "data", ("pod",))
 
         specs = P("pod", "data")
-        run = lambda f: jax.jit(shard_map_compat(
+        run = lambda f: jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=specs, out_specs=specs,
             axis_names={"pod", "data"}, check_vma=False))(x)
         a, b = run(flat), run(tree)
@@ -62,12 +62,12 @@ def check_compressed_exact_when_k_full():
     def flat(xl):
         return coll.flat_allreduce(xl, ("data", "pod"))
 
-    got, nr = jax.jit(shard_map_compat(
+    got, nr = jax.jit(jax.shard_map(
         cmp_fn, mesh=mesh,
         in_specs=(P("pod", "data"), P("pod", "data", "model")),
         out_specs=(P("pod", "data"), P("pod", "data", "model")),
         axis_names={"pod", "data", "model"}, check_vma=False))(x, res0)
-    want = jax.jit(shard_map_compat(
+    want = jax.jit(jax.shard_map(
         flat, mesh=mesh, in_specs=P("pod", "data"), out_specs=P("pod", "data"),
         axis_names={"pod", "data"}, check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
@@ -89,7 +89,7 @@ def check_compressed_with_fpe_node():
             fpe_capacity=16)  # tiny FPE: heavy eviction path
         return out.reshape(xl.shape), nr.reshape(rl.shape)
 
-    got, _ = jax.jit(shard_map_compat(
+    got, _ = jax.jit(jax.shard_map(
         cmp_fn, mesh=mesh,
         in_specs=(P("pod", "data"), P("pod", "data", "model")),
         out_specs=(P("pod", "data"), P("pod", "data", "model")),
@@ -98,7 +98,7 @@ def check_compressed_with_fpe_node():
     def flat(xl):
         return coll.flat_allreduce(xl, ("data", "pod"))
 
-    want = jax.jit(shard_map_compat(
+    want = jax.jit(jax.shard_map(
         flat, mesh=mesh, in_specs=P("pod", "data"), out_specs=P("pod", "data"),
         axis_names={"pod", "data"}, check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)

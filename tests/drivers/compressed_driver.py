@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs.reduced import reduced_config
 from repro.core.collectives import GradAggMode
 from repro.data.pipeline import DataConfig, SyntheticLMData
+from repro.launch.mesh import make_mesh
 from repro.models.model import LMModel
 from repro.optim import AdamWConfig, adamw_init, make_lr_schedule
 from repro.train.compressed import build_compressed_train_step
@@ -29,7 +30,7 @@ CFG = dataclasses.replace(reduced_config("phi4-mini-3.8b"), dtype="float32")
 DATA = SyntheticLMData(CFG, DataConfig(seq_len=16, global_batch=8, seed=0))
 OPT = AdamWConfig(master_fp32=False)
 LR = make_lr_schedule(1e-3, 2, 100)
-MESH = jax.make_mesh((2, 2, 2), ("data", "pod", "model"))
+MESH = make_mesh((2, 2, 2), ("data", "pod", "model"))
 PROF = TrainProfile(dp_axes=("data", "pod"), tp_axis="model",
                     q_chunk=16, k_chunk=16, moe_token_chunk=16,
                     remat="none", mode=GradAggMode.TREE_COMPRESS)

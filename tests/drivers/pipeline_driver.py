@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import make_mesh
 from repro.train.pipeline import bubble_fraction, make_gpipe_fn
 
 assert jax.device_count() == 8
@@ -15,7 +16,7 @@ assert jax.device_count() == 8
 
 def check_pipeline_matches_sequential():
     n_stages, m, mb, d = 8, 16, 4, 32
-    mesh = jax.make_mesh((8,), ("stage",))
+    mesh = make_mesh((8,), ("stage",))
     rng = np.random.default_rng(0)
     # per-stage params: one linear + nonlinearity per stage
     w = jnp.asarray(rng.standard_normal((n_stages, d, d)).astype(np.float32) * 0.2)

@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.configs.reduced import reduced_config
 from repro.data.pipeline import DataConfig, SyntheticLMData
+from repro.launch.mesh import make_mesh
 from repro.models.model import LMModel
 from repro.models import transformer as tfm
 from repro.train.step import TrainProfile, build_prefill_step, build_serve_step
@@ -41,7 +42,7 @@ def check_sharded_decode(arch, batch_size, batch_shardable):
     if cfg.moe is not None:
         cfg = dataclasses.replace(
             cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     prof = TrainProfile(dp_axes=("data",), tp_axis="model",
                         q_chunk=8, k_chunk=8, moe_token_chunk=64, remat="none")
     n_pre, n_dec, cache_len = 8, 5, 32

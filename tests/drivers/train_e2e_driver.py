@@ -16,6 +16,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.reduced import reduced_config
 from repro.core.collectives import GradAggMode
 from repro.data.pipeline import DataConfig, SyntheticLMData
+from repro.launch.mesh import make_mesh
 from repro.models.model import LMModel
 from repro.optim import AdamWConfig, adamw_init, make_lr_schedule
 from repro.train.step import TrainProfile, build_train_step
@@ -57,7 +58,7 @@ def run_steps(step_fn, params, opt, start, n):
 
 
 def check_modes_agree():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     results = {}
     for mode in (GradAggMode.FLAT, GradAggMode.TREE, GradAggMode.GATHER):
         step_fn, params, opt, _ = build(mesh, mode)
@@ -77,7 +78,7 @@ def check_modes_agree():
 
 
 def check_checkpoint_restart_and_elastic():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     step_fn, params, opt, shardings = build(mesh, GradAggMode.TREE)
     params, opt, l1 = run_steps(step_fn, params, opt, 0, 3)
     ckdir = tempfile.mkdtemp(prefix="ckpt_")
@@ -100,7 +101,7 @@ def check_checkpoint_restart_and_elastic():
 
     # ELASTIC: restart the same checkpoint on a DIFFERENT mesh (no pod axis,
     # 4-wide data) — checkpoints are mesh-agnostic full arrays.
-    mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2 = make_mesh((4, 2), ("data", "model"))
     step_fn3, params0, opt0, sh3 = build(mesh2, GradAggMode.TREE)
     restored2, _ = mgr.restore({"params": params0, "opt": opt0})
     params_c = jax.device_put(restored2["params"], sh3["params"])

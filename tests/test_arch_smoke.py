@@ -17,6 +17,7 @@ import pytest
 import repro.configs as configs
 from repro.configs.reduced import reduced_config
 from repro.data.pipeline import DataConfig, SyntheticLMData
+from repro.launch.mesh import make_mesh
 from repro.models.layers import lm_logits, rms_norm
 from repro.models.model import LMModel
 from repro.models import transformer as tfm
@@ -53,7 +54,7 @@ def _dense_logits(model, params, batch):
 @pytest.mark.parametrize("arch", ARCHS)
 def test_train_step_smoke(arch):
     cfg = _cfg(arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # chunks of 8 divide both 16 (text) and 16+8 (vision-prefixed) sequences
     prof = TrainProfile(q_chunk=8, k_chunk=8, moe_token_chunk=32, remat="none")
     model = LMModel(cfg)

@@ -1,16 +1,16 @@
 """Roofline math + collective accounting (the §Roofline source of truth)."""
 
-import jax
 import pytest
 
 import repro.configs as configs
 from repro.configs.base import shape_by_name
 from repro.launch import hlo_analysis as ha
-from repro.launch.mesh import DCN_BW, HBM_BW, ICI_BW, ICI_LINKS, PEAK_FLOPS_BF16
+from repro.launch.mesh import (DCN_BW, HBM_BW, ICI_BW, ICI_LINKS,
+                               PEAK_FLOPS_BF16, make_mesh)
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_roofline_terms_math():
@@ -30,7 +30,7 @@ def test_roofline_terms_math():
 def test_collectives_level_accounting():
     """all-reduce over (data,pod): ring bytes at data level, shard/16 at pod."""
     mesh512 = type("M", (), {})()  # fake mesh-like for sizes
-    real = jax.make_mesh((1, 1), ("data", "model"))
+    real = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         axis_names = ("pod", "data", "model")

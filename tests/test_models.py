@@ -236,3 +236,25 @@ def test_moe_router_weights_normalized(rng):
     w = jax.nn.softmax(logits, axis=-1)
     topw, _ = jax.lax.top_k(w, cfg.moe.top_k)
     assert np.all(np.asarray(topw.sum(-1)) <= 1.0 + 1e-6)
+
+
+def test_mamba_gradient_finite_over_a_long_decaying_chunk():
+    """Strong decay over a 256-step chunk overflows exp(csum_i - csum_j)
+    above the diagonal; the masked entries must not turn the backward
+    pass into NaN (they did at mamba2-780m's published chunk)."""
+    from repro.configs.base import MambaConfig
+    from repro.models import mamba
+
+    cfg = _mini_cfg(family="ssm", n_heads=0, n_kv_heads=0, head_dim=0,
+                    d_ff=0, pattern=(LayerSpec("mamba", "none"),),
+                    mamba=MambaConfig(d_state=16, head_dim=16, chunk=256))
+    p = mamba.init_mamba_params(jax.random.PRNGKey(0), cfg, F32)
+    p["dt_bias"] = jnp.full_like(p["dt_bias"], 2.0)  # dt ~ 2 per step
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 256, cfg.d_model), F32)
+
+    def loss(p, x):
+        return jnp.sum(mamba.mamba_dense(x, p, cfg) ** 2)
+
+    gp, gx = jax.grad(loss, argnums=(0, 1))(p, x)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in jax.tree.leaves(gp))
+    assert bool(jnp.all(jnp.isfinite(gx)))

@@ -6,44 +6,30 @@ tree, end-to-end training in every exchange mode, checkpoint/elastic
 restart, and TP+cache-sharded serving.
 """
 
-import jax
 import pytest
 
 from conftest import run_driver
 
-# The dataplane drivers run shard_map manual over (pod, data) while the
-# model axis stays auto.  jax releases without `jax.shard_map` only offer
-# the experimental partial-auto path, whose SPMD partitioning crashes
-# (fatal CHECK in spmd_partitioner.cc) — requires a jax with the stable API.
-partial_auto = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-auto shard_map needs the stable jax.shard_map API",
-)
-
 
 @pytest.mark.integration
-@partial_auto
 def test_collectives_dataplane():
     out = run_driver("collectives_driver")
     assert "ALL OK" in out
 
 
 @pytest.mark.integration
-@partial_auto
 def test_train_e2e_modes_checkpoint_elastic():
     out = run_driver("train_e2e_driver", timeout=600)
     assert "ALL OK" in out
 
 
 @pytest.mark.integration
-@partial_auto
 def test_sharded_serving():
     out = run_driver("serve_driver", timeout=600)
     assert "ALL OK" in out
 
 
 @pytest.mark.integration
-@partial_auto
 def test_compressed_exchange_training():
     out = run_driver("compressed_driver", timeout=600)
     assert "lossless limit OK" in out

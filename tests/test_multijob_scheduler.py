@@ -13,6 +13,7 @@ import pytest
 from repro.core import planner as pl
 from repro.core import tree as tree_lib
 from repro.core.collectives import GradAggMode
+from repro.launch.mesh import make_mesh
 
 MiB = 1 << 20
 
@@ -215,7 +216,7 @@ def test_modeled_level_bytes_compress_shrinks_uppers_only():
 def test_topology_from_mesh_skips_absent_axes():
     import jax
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     topo = pl.Topology.from_mesh(mesh)
     assert len(topo.links) == 1  # degenerate but total
 
@@ -237,7 +238,7 @@ def test_report_summary_mentions_every_job():
 def test_plan_grad_exchange_reports_level_bytes():
     import jax
 
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     plan = pl.plan_grad_exchange(mesh, grad_bytes=64 * MiB,
                                  reduce_axes=("data", "model"))
     if plan.fanins and math.prod(plan.fanins) > 1:
@@ -272,7 +273,7 @@ def test_exchange_from_plan_drives_dataplane():
 
     from repro.core import collectives as coll
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plan = pl.plan_grad_exchange(mesh, reduce_axes=("data", "model"))
     assert plan.mode == GradAggMode.TREE and plan.upper_axes == ()
 
@@ -280,7 +281,7 @@ def test_exchange_from_plan_drives_dataplane():
         out, _ = coll.exchange_from_plan(g, plan)
         return out
 
-    mapped = coll.shard_map_compat(
+    mapped = jax.shard_map(
         region, mesh=mesh, in_specs=(P(),), out_specs=P(),
         axis_names={"data", "model"}, check_vma=False)
     x = {"w": jnp.arange(8.0)}

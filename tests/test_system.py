@@ -1,5 +1,7 @@
 """System-level coherence: registry, cells, public imports, mesh factory."""
 
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -66,6 +68,41 @@ def test_mesh_factory_is_lazy():
     assert callable(m.make_production_mesh)
     assert m.PEAK_FLOPS_BF16 == 197e12
     assert m.HBM_BW == 819e9
+
+
+def test_make_mesh_axes_are_auto():
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(from_env, tmp_path, monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache sits
+    at the checkout's own gitignored .jax_cache."""
+    from conftest import REPO
+    from repro.launch.compile_cache import enable_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        where = enable_compile_cache()
+        if from_env:
+            assert where == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            assert where == os.path.join(REPO, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == where
+            with open(os.path.join(REPO, ".gitignore")) as f:
+                assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_vocab_shards_over_model_axis():

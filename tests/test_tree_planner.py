@@ -1,10 +1,10 @@
 """Aggregation tree + controller/planner (the paper's control plane)."""
 
-import jax
 import pytest
 
 from repro.core import planner, tree as tree_lib
 from repro.core.collectives import GradAggMode
+from repro.launch.mesh import make_mesh
 from repro.runtime.fault_tolerance import StragglerMonitor
 
 
@@ -14,7 +14,7 @@ from repro.runtime.fault_tolerance import StragglerMonitor
 
 
 def test_from_mesh_single_device():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     t = tree_lib.from_mesh(mesh)
     assert t.fanin == 1  # degenerate but total
 
@@ -72,7 +72,7 @@ def test_controller_carries_tree_shape():
 
 
 def test_plan_grad_exchange_single_device():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plan = planner.plan_grad_exchange(mesh, mode=GradAggMode.TREE,
                                       grad_bytes=1 << 20)
     assert plan.mode == GradAggMode.TREE
