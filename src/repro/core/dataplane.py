@@ -43,6 +43,10 @@ from . import reduction_model as rm
 
 EMPTY_KEY = kvagg.EMPTY_KEY
 
+# compiles and persistent-cache loads feed the registry (DESIGN.md §11)
+jax.monitoring.register_event_duration_secs_listener(
+    obs_metrics.record_compile)
+
 
 @dataclasses.dataclass(frozen=True)
 class LevelSpec:
@@ -344,7 +348,11 @@ class LevelState:
 
     Telemetry mirrors :class:`LevelStats`: ``n_in`` real pairs ingested,
     ``n_evict`` FPE evictions, ``n_out`` pairs forwarded downstream
-    (per-batch BPE-combined evictions when ``spec.bpe``, plus the flush).
+    (per-batch BPE-combined evictions when ``spec.bpe``, plus the flush);
+    ``n_slots`` counts the padded input slots the FPE and BPE dispatches
+    walked, real or empty.  ``level`` is the node's place in its cascade,
+    the ``level`` arg of its ``dataplane.fpe`` / ``bpe`` / ``fetch`` spans
+    (DESIGN.md §11).
     """
 
     #: pending-row count above which the capacity-0 node compacts its
@@ -356,8 +364,10 @@ class LevelState:
     MIN_PAD = 8
 
     def __init__(self, spec: LevelSpec, op: str, *,
-                 batch_pad: int | None = None, exact_stream: bool = True):
+                 batch_pad: int | None = None, exact_stream: bool = True,
+                 level: int = 0):
         self.spec = spec
+        self.level = level
         self.op = op
         self._aggop = aggops.get(op)
         self.batch_pad = batch_pad
@@ -373,6 +383,7 @@ class LevelState:
         self.n_in = 0
         self.n_evict = 0
         self.n_out = 0
+        self.n_slots = 0
         self._flushed = False
 
     def _empty_out(self) -> tuple[np.ndarray, np.ndarray]:
@@ -394,8 +405,9 @@ class LevelState:
         if self._value_sample is None and values.shape[0]:
             self._value_sample = np.zeros(values.shape[1:], values.dtype)
         real = keys != _EMPTY
-        self.n_in += int(real.sum())
-        if not real.any():
+        n_real = int(real.sum())
+        self.n_in += n_real
+        if not n_real:
             return self._empty_out()
         if not self.spec.enabled:  # forward-only hop (DESIGN.md §9)
             fk, fv = keys[real].astype(np.int32), values[real]
@@ -403,7 +415,7 @@ class LevelState:
             return fk, fv
         if self._exact is not None:  # capacity == 0: exact unbounded node
             self._exact.append((keys[real], values[real]))
-            self._exact_rows += int(real.sum())
+            self._exact_rows += n_real
             if self._exact_rows > self.COMPACT_THRESHOLD:
                 self._compact_exact()
             return self._empty_out()
@@ -416,8 +428,10 @@ class LevelState:
                       1 << (int(keys.shape[0]) - 1).bit_length())
         out_k, out_v = [], []
         for lo in range(0, keys.shape[0], pad):
+            chunk_real = (n_real if keys.shape[0] <= pad
+                          else int(real[lo:lo + pad].sum()))
             ek, ev = self._ingest_chunk(keys[lo:lo + pad],
-                                        values[lo:lo + pad], pad)
+                                        values[lo:lo + pad], pad, chunk_real)
             if ek.size:
                 out_k.append(ek)
                 out_v.append(ev)
@@ -428,25 +442,41 @@ class LevelState:
         return fk, fv
 
     def _ingest_chunk(self, keys: np.ndarray, values: np.ndarray,
-                      pad: int) -> tuple[np.ndarray, np.ndarray]:
+                      pad: int, n_real: int) -> tuple[np.ndarray, np.ndarray]:
+        tracer = obs_trace.get_tracer()
+        level = self.level
         if keys.shape[0] < pad:
             fill = pad - keys.shape[0]
             keys = np.concatenate(
                 [keys, np.full((fill,), _EMPTY, np.int32)])
             values = np.concatenate(
                 [values, np.zeros((fill,) + values.shape[1:], values.dtype)])
-        res = kvagg.fpe_aggregate(
-            jnp.asarray(keys), jnp.asarray(values),
-            capacity=self.spec.capacity, ways=self.spec.ways, op=self.op,
-            exact_stream=self.exact_stream,
-            table_keys=self._tk, table_values=self._tv)
+        with tracer.span("dataplane.fpe", cat="dataplane",
+                         args={"level": level, "slots": pad, "real": n_real}):
+            res = kvagg.fpe_aggregate(
+                jnp.asarray(keys), jnp.asarray(values),
+                capacity=self.spec.capacity, ways=self.spec.ways, op=self.op,
+                exact_stream=self.exact_stream,
+                table_keys=self._tk, table_values=self._tv)
         self._tk, self._tv = res.table_keys, res.table_values
-        self.n_evict += int(np.sum(np.asarray(res.evict_keys) != _EMPTY))
+        self.n_slots += pad
+        with tracer.span("dataplane.fetch", cat="dataplane",
+                         args={"level": level, "what": "evict_count"}):
+            evicted = np.asarray(res.evict_keys)
+        n_evict = int(np.sum(evicted != _EMPTY))
+        self.n_evict += n_evict
         ek, ev = res.evict_keys, res.evict_values
         if self.spec.bpe:  # combine this packet's evictions (fixed shape)
-            c = kvagg.sorted_combine(ek, ev, op=self.op)
+            slots = int(ek.shape[0])
+            with tracer.span("dataplane.bpe", cat="dataplane",
+                             args={"level": level, "slots": slots,
+                                   "real": n_evict}):
+                c = kvagg.sorted_combine(ek, ev, op=self.op)
             ek, ev = c.unique_keys, c.combined_values
-        ek, ev = np.asarray(ek), np.asarray(ev)
+            self.n_slots += slots
+        with tracer.span("dataplane.fetch", cat="dataplane",
+                         args={"level": level, "what": "evictions"}):
+            ek, ev = np.asarray(ek), np.asarray(ev)
         mask = ek != _EMPTY
         return ek[mask], ev[mask]
 
@@ -462,9 +492,12 @@ class LevelState:
             k = np.concatenate([k, np.full((pad,), _EMPTY, np.int32)])
             v = np.concatenate([v, np.zeros((pad,) + v.shape[1:], v.dtype)])
         c = kvagg.sorted_combine(jnp.asarray(k), jnp.asarray(v), op=self.op)
-        nu = int(c.n_unique)
-        ck = np.asarray(c.unique_keys)[:nu]
-        cv = np.asarray(c.combined_values)[:nu]
+        with obs_trace.get_tracer().span(
+                "dataplane.fetch", cat="dataplane",
+                args={"level": self.level, "what": "compact"}):
+            nu = int(c.n_unique)
+            ck = np.asarray(c.unique_keys)[:nu]
+            cv = np.asarray(c.combined_values)[:nu]
         self._exact = [(ck, cv)]
         self._exact_rows = nu
 
@@ -479,7 +512,10 @@ class LevelState:
         elif self._tk is None:
             return self._empty_out()
         else:
-            tk, tv = np.asarray(self._tk), np.asarray(self._tv)
+            with obs_trace.get_tracer().span(
+                    "dataplane.fetch", cat="dataplane",
+                    args={"level": self.level, "what": "table"}):
+                tk, tv = np.asarray(self._tk), np.asarray(self._tv)
             mask = tk != _EMPTY
             fk, fv = tk[mask].astype(np.int32), tv[mask]
         self.n_out += fk.shape[0]
@@ -512,11 +548,17 @@ def run_cascade_stream(
     arbitrary packet lengths compiles O(log max_len) FPE traces, not one
     per distinct length (DESIGN.md §8).  ``exact_stream=False`` runs all
     node FPEs on the batched-block fast path.
+
+    Spans (DESIGN.md §11): ``dataplane.ingest`` per batch (args ``batch``,
+    ``records``), inside it ``dataplane.level`` per node ingest and each
+    blocking device-to-host read as ``dataplane.fetch``; then
+    ``dataplane.flush`` per level and ``dataplane.root_combine``.
     """
     op = aggops.get(plan.op)
+    tracer = obs_trace.get_tracer()
     states = [LevelState(spec, plan.op, batch_pad=batch_pad,
-                         exact_stream=exact_stream)
-              for spec in plan.levels]
+                         exact_stream=exact_stream, level=i)
+              for i, spec in enumerate(plan.levels)]
     root_k: list[np.ndarray] = []
     root_v: list[np.ndarray] = []
 
@@ -527,17 +569,26 @@ def run_cascade_stream(
             root_k.append(np.asarray(k, np.int32))
             root_v.append(np.asarray(v))
             return
-        ek, ev = states[i].ingest(k, v)
+        with tracer.span("dataplane.level", cat="dataplane",
+                         args={"level": i}):
+            ek, ev = states[i].ingest(k, v)
         push(i + 1, ek, ev)
 
-    with obs_trace.get_tracer().span("dataplane.run_cascade_stream",
-                                     cat="dataplane"):
-        for k, v in batches:
-            v = np.asarray(op.prepare_values(jnp.asarray(v))) if prepare \
-                else np.asarray(v)
-            push(0, np.asarray(k, np.int32), v)
+    with tracer.span("dataplane.run_cascade_stream", cat="dataplane"):
+        for b, (k, v) in enumerate(batches):
+            with tracer.span("dataplane.ingest", cat="dataplane",
+                             args={"batch": b, "records": len(k)}):
+                if prepare:
+                    with tracer.span("dataplane.fetch", cat="dataplane",
+                                     args={"what": "prepare"}):
+                        v = np.asarray(op.prepare_values(jnp.asarray(v)))
+                else:
+                    v = np.asarray(v)
+                push(0, np.asarray(k, np.int32), v)
         for i, st in enumerate(states):
-            fk, fv = st.flush()
+            with tracer.span("dataplane.flush", cat="dataplane",
+                             args={"level": i}):
+                fk, fv = st.flush()
             push(i + 1, fk, fv)
 
     if root_k:
@@ -554,10 +605,12 @@ def run_cascade_stream(
             rv = np.asarray(op.prepare_values(jnp.zeros((0,), jnp.float32)))
         else:
             rv = np.zeros((0,), np.float32)
-    k_out, v_out = jnp.asarray(rk), jnp.asarray(rv)
-    if final_combine and rk.size:
-        c = kvagg.sorted_combine(k_out, v_out, op=plan.op)
-        k_out, v_out = c.unique_keys, c.combined_values
+    with tracer.span("dataplane.root_combine", cat="dataplane",
+                     args={"records": int(rk.shape[0])}):
+        k_out, v_out = jnp.asarray(rk), jnp.asarray(rv)
+        if final_combine and rk.size:
+            c = kvagg.sorted_combine(k_out, v_out, op=plan.op)
+            k_out, v_out = c.unique_keys, c.combined_values
     if finalize:
         v_out = op.finalize_values(v_out)
     i32 = lambda xs: jnp.asarray(np.asarray(xs, np.int32))  # noqa: E731
@@ -565,10 +618,9 @@ def run_cascade_stream(
         plan.op,
         [{"level": i, "records_in": int(s.n_in),
           "records_out": int(s.n_out), "evictions": int(s.n_evict),
+          "slots": int(s.n_slots),
           "reduction": round(1.0 - int(s.n_out) / max(int(s.n_in), 1), 4)}
          for i, s in enumerate(states)],
-        end_to_end=round(1.0 - int(states[-1].n_out)
-                         / max(int(states[0].n_in), 1), 4),
         source="stream")
     return CascadeResult(
         keys=k_out, values=v_out,
@@ -607,8 +659,7 @@ def predicted_level_reductions(
     return preds
 
 
-def _publish_levels(op_name: str, levels: list, *, end_to_end: float,
-                    source: str) -> None:
+def _publish_levels(op_name: str, levels: list, *, source: str) -> None:
     """Per-level cascade telemetry into the obs registry (DESIGN.md §11).
 
     ``run_cascade`` is jitted, so publishing happens at its observation
@@ -625,11 +676,13 @@ def _publish_levels(op_name: str, levels: list, *, end_to_end: float,
                     **lbl).inc(lvl["records_out"])
         reg.counter("dataplane.level.evictions_total",
                     **lbl).inc(lvl["evictions"])
+        if "slots" in lvl:
+            reg.counter("dataplane.level.slots_total",
+                        **lbl).inc(lvl["slots"])
         reg.gauge("dataplane.level.reduction", **lbl).set(lvl["reduction"])
         if "predicted_reduction" in lvl:
             reg.gauge("dataplane.level.predicted_reduction",
                       **lbl).set(lvl["predicted_reduction"])
-    reg.gauge("dataplane.end_to_end_reduction", **base).set(end_to_end)
 
 
 def telemetry(res: CascadeResult, plan: CascadePlan) -> dict:
@@ -654,9 +707,7 @@ def telemetry(res: CascadeResult, plan: CascadePlan) -> dict:
         "n_out": int(res.n_out),
         "end_to_end_reduction": round(float(end_to_end_reduction(res)), 4),
     }
-    _publish_levels(plan.op, levels,
-                    end_to_end=report["end_to_end_reduction"],
-                    source="cascade")
+    _publish_levels(plan.op, levels, source="cascade")
     return report
 
 

@@ -234,6 +234,7 @@ def fpe_aggregate_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="fpe_aggregate_pallas",
     )(keys.reshape(n_blocks, 1, block_n),
       values.reshape(lanes, n_blocks, 1, block_n))
     # lane-dense tiles -> the oracle's flat [n_buckets * ways] slot order

@@ -113,7 +113,7 @@ class _Node:
         self.aggregate = aggregate and (spec is None or spec.enabled)
         self.state = (dataplane.LevelState(
             spec, op, batch_pad=cfg.records_per_packet,
-            exact_stream=cfg.exact_stream)
+            exact_stream=cfg.exact_stream, level=level)
             if self.aggregate else None)
         self.receiver = transport.Receiver()
         self.proc_free = 0.0

@@ -38,6 +38,7 @@ __all__ = [
     "scoped",
     "instrument_step",
     "InstrumentedStep",
+    "record_compile",
 ]
 
 
@@ -238,3 +239,21 @@ class InstrumentedStep:
 def instrument_step(fn: Callable, name: str = "train.step",
                     labels: Optional[dict] = None) -> InstrumentedStep:
     return InstrumentedStep(fn, name=name, labels=labels)
+
+
+#: JAX's event around compiling one program or loading it from its cache
+JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def record_compile(event: str, duration_s: float, **kw) -> None:
+    """``jax.monitoring`` duration listener, installed when
+    ``repro.core.dataplane`` is imported: each program JAX compiles or
+    loads from its persistent cache adds one to ``jax.compiles_total``
+    and its seconds to the histogram ``jax.compile_s``, labelled ``fun``
+    where JAX names the function."""
+    if event != JAX_COMPILE_EVENT:
+        return
+    labels = {"fun": kw["fun_name"]} if "fun_name" in kw else {}
+    reg = get_registry()
+    reg.counter("jax.compiles_total", **labels).inc()
+    reg.histogram("jax.compile_s", **labels).observe(duration_s)

@@ -20,11 +20,22 @@ Two kinds of time coexist in one trace:
 Timestamps are exported in microseconds (the trace-event unit);
 fractional values are allowed and preserved.
 
-Zero overhead when disabled: ``span()`` returns a module-level no-op
-singleton without allocating, and ``add_span``/``instant`` return before
-touching any state.  ``tests/test_obs.py`` pins both the zero-entry and
-the zero-allocation behaviour; ``bench_sim.py``'s ``obs_overhead`` cell
-floor-gates the throughput of the disabled path in CI.
+A wall span has a second sink: while a JAX profiler capture is running
+(``jax.profiler.start_trace`` / ``trace``), ``span()`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name, whatever the tracer's
+own state, with the span's args as the event's stats.  The span then
+lands on the host plane of the captured ``.xplane.pb``, on the same
+clock as the device's operations.  Capturing a profile is the only
+switch; jax is looked up only once something else has imported it.
+Each collection of Python's cyclic garbage collector is a ``python.gc``
+span (arg ``generation``) in both sinks while either is on.
+
+Zero overhead when disabled: with the tracer off and no capture running,
+``span()`` returns a module-level no-op singleton without allocating, and
+``add_span`` returns before touching any state.  ``tests/test_obs.py``
+pins both the zero-entry and the zero-allocation behaviour;
+``bench_sim.py``'s ``obs_overhead`` cell floor-gates the throughput of
+the disabled path in CI.
 
 Stdlib-only on purpose: every layer (core, net, train, tools) may import
 this module without creating cycles or dragging in jax.
@@ -33,7 +44,9 @@ this module without creating cycles or dragging in jax.
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
+import sys
 import time
 from typing import Any, Iterator, Optional
 
@@ -44,6 +57,7 @@ __all__ = [
     "scoped_tracer",
     "enable",
     "disable",
+    "capturing",
 ]
 
 #: wall-clock track: every ``span()`` context-manager lands here.
@@ -66,11 +80,32 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation``, once jax has been imported
+_annotation = None
+
+
+def capturing() -> bool:
+    """Whether a JAX profiler capture is running.  Never imports jax: a
+    process that has not imported it cannot be capturing."""
+    global _annotation
+    if _annotation is None:
+        profiler = sys.modules.get("jax.profiler")
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+        if _annotation is None:
+            return False
+    return _annotation.is_enabled()
+
+
+def _annotate(name: str, args: Optional[dict]):
+    """A TraceAnnotation for the running capture; args become its stats."""
+    return _annotation(name, **args) if args else _annotation(name)
+
 
 class _Span:
-    """Live wall-clock span; appends one complete ("X") event on exit."""
+    """Live wall-clock span; appends one complete ("X") event on exit, and
+    annotates the running JAX profiler capture, if any."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_tid", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_tid", "_t0", "_ann")
 
     def __init__(self, tracer, name, cat, args, tid):
         self._tracer = tracer
@@ -78,15 +113,20 @@ class _Span:
         self._cat = cat
         self._args = args
         self._tid = tid
+        self._ann = _annotate(name, args) if capturing() else None
         self._t0 = time.perf_counter()
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
         tr = self._tracer
         t0 = (self._t0 - tr._epoch) * 1e6
         dur = (time.perf_counter() - self._t0) * 1e6
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         ev = {"name": self._name, "cat": self._cat, "ph": "X",
               "ts": t0, "dur": dur, "pid": WALL_PID, "tid": self._tid}
         if self._args:
@@ -125,10 +165,14 @@ class Tracer:
     # -- recording ---------------------------------------------------------
     def span(self, name: str, cat: str = "wall", args: Optional[dict] = None,
              tid: int = 0):
-        """Wall-clock span context manager (no-op singleton when disabled)."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, cat, args, tid)
+        """Wall-clock span context manager.  While a JAX profiler capture
+        runs it also annotates the capture, enabled or not; with neither,
+        the no-op singleton."""
+        if self.enabled:
+            return _Span(self, name, cat, args, tid)
+        if capturing():
+            return _annotate(name, args)
+        return _NULL_SPAN
 
     def add_span(self, name: str, t0_s: float, t1_s: float, *,
                  cat: str = "sim", pid: int = WALL_PID, tid: int = 0,
@@ -157,19 +201,6 @@ class Tracer:
               "ts": (t0_perf - self._epoch) * 1e6,
               "dur": max(t1_perf - t0_perf, 0.0) * 1e6,
               "pid": WALL_PID, "tid": tid}
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
-
-    def instant(self, name: str, *, t_s: Optional[float] = None,
-                cat: str = "wall", pid: int = WALL_PID, tid: int = 0,
-                args: Optional[dict] = None) -> None:
-        """Record an instant ("i") event; wall-clock 'now' if t_s is None."""
-        if not self.enabled:
-            return
-        ts = ((time.perf_counter() - self._epoch) if t_s is None else t_s)
-        ev = {"name": name, "cat": cat, "ph": "i", "s": "t",
-              "ts": ts * 1e6, "pid": pid, "tid": tid}
         if args:
             ev["args"] = args
         self.events.append(ev)
@@ -243,3 +274,26 @@ def enable() -> Tracer:
 def disable() -> Tracer:
     _TRACER.disable()
     return _TRACER
+
+
+# -- Python's cyclic garbage collector ---------------------------------------
+
+_gc_span = None  # the open ``python.gc`` span of the collection under way
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``python.gc`` span per collection, in
+    whichever sinks are on (none: nothing is allocated)."""
+    global _gc_span
+    if phase == "start":
+        if _TRACER.enabled or capturing():
+            _gc_span = _TRACER.span("python.gc", cat="gc",
+                                    args={"generation": info["generation"]})
+            _gc_span.__enter__()
+    elif _gc_span is not None:
+        span, _gc_span = _gc_span, None
+        span.__exit__(None, None, None)
+
+
+if _on_gc not in gc.callbacks:
+    gc.callbacks.append(_on_gc)
