@@ -95,7 +95,8 @@ class AggOp:
     name: str
     combine: Callable  # (a, b) -> merged, elementwise per carried lane
     identity: Callable  # dtype -> scalar neutral element (carried dtype)
-    segment_reduce: Callable  # (values, segment_ids, num_segments) -> [S,...]
+    # (values, segment_ids, num_segments, indices_are_sorted=False) -> [S,...]
+    segment_reduce: Callable
     lanes: int = 1
     prepare: Callable | None = None  # user values -> carried values
     finalize: Callable | None = None  # carried values -> user values
@@ -149,13 +150,15 @@ def names() -> tuple[str, ...]:
 # ---------------------------------------------------------------------------
 
 
-def _segment_logsumexp(values, segment_ids, num_segments):
+def _segment_logsumexp(values, segment_ids, num_segments,
+                       indices_are_sorted=False):
     """Numerically stable segmented logsumexp (two-pass max-shift)."""
-    m = jax.ops.segment_max(values, segment_ids, num_segments=num_segments)
+    m = jax.ops.segment_max(values, segment_ids, num_segments=num_segments,
+                            indices_are_sorted=indices_are_sorted)
     m_safe = jnp.where(jnp.isfinite(m), m, jnp.zeros_like(m))
     s = jax.ops.segment_sum(
         jnp.exp(values - m_safe[segment_ids]), segment_ids,
-        num_segments=num_segments)
+        num_segments=num_segments, indices_are_sorted=indices_are_sorted)
     out = m_safe + jnp.log(s)
     neg_inf = jnp.array(-jnp.inf, values.dtype)
     return jnp.where(s > 0, out, neg_inf)

@@ -36,6 +36,7 @@ totals but a different eviction pattern, ~5-8x the scan's pairs/sec.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -183,8 +184,8 @@ def _fpe_scan(
 
 
 def _group_reduce(keys, values, *, op):
-    """THE bulk group-by-key reduction (DESIGN.md §8): one radix key sort +
-    a binary-search segment-id map + one unsorted segment reduce.
+    """THE bulk group-by-key reduction (DESIGN.md §8): a key sort, a map
+    from every element to its group, one segment reduce.
 
     Returns (k_s, real_start, comb):
       k_s        [n] keys sorted ascending (EMPTY_KEY is just another value
@@ -194,20 +195,78 @@ def _group_reduce(keys, values, *, op):
                  the key's FIRST SORTED POSITION (entries that are not a
                  real first occurrence hold garbage — never read).
 
-    Why this shape: on XLA:CPU the single-operand int sort takes the fast
-    radix path while the variadic comparator sort that would co-sort
-    values with keys is ~10x slower, and scatters cost ~30x a gather.  So
-    values never ride a sort: each ORIGINAL element finds its group with
-    one searchsorted pass and the reduce runs over unsorted segment ids.
+    The group map is chosen by the platform the program is lowered for
+    (``lax.platform_dependent``, so a compile for a described TPU takes
+    the TPU branch too); both branches return the same shapes, dtypes and
+    first-position totals:
+
+    * XLA:CPU (``_group_reduce_searched``): the single-operand int sort
+      takes the fast radix path while a variadic sort that co-sorts values
+      is several times slower, and scatters cost ~30x a gather.  So values
+      never ride a sort: each ORIGINAL element finds its group with one
+      ``searchsorted`` pass and the reduce runs over unsorted segment ids
+      (3-4x faster than the other branch there; re-measure with
+      ``benchmarks/bench_group_map.py``).
+    * Every other backend (``_group_reduce_sorted``): that ``searchsorted``
+      is a loop of log2(n) steps of one dynamic gather over the whole
+      stream each, which took most of the TPU's busy time, while a sort
+      that carries the values is cheap there.  So the values ride the key
+      sort and each sorted element takes the first position of its run
+      from a running max: no loop, and the reduce's ids arrive sorted.
     """
+    return jax.lax.platform_dependent(
+        keys, values,
+        cpu=functools.partial(_group_reduce_searched, op=op),
+        default=functools.partial(_group_reduce_sorted, op=op))
+
+
+def _run_change(k_s):
+    """[n] True where a sorted key differs from its predecessor."""
+    return jnp.concatenate([jnp.ones((1,), bool), k_s[1:] != k_s[:-1]])
+
+
+def _group_reduce_searched(keys, values, *, op):
+    """``_group_reduce`` for XLA:CPU: radix sort + binary-search group map
+    + unsorted segment reduce."""
     aggop = aggops.get(op)
     n = keys.shape[0]
     k_s = jnp.sort(keys)
-    change = jnp.concatenate([jnp.ones((1,), bool), k_s[1:] != k_s[:-1]])
-    real_start = change & (k_s != EMPTY_KEY)
+    real_start = _run_change(k_s) & (k_s != EMPTY_KEY)
     seg = jnp.searchsorted(k_s, keys, method="scan")
     comb = aggop.segment_reduce(values, seg, num_segments=n)
     return k_s, real_start, comb
+
+
+def _group_reduce_sorted(keys, values, *, op):
+    """``_group_reduce`` off the CPU: one sort carrying every value lane as
+    an operand, run starts by a running max, sorted segment reduce."""
+    aggop = aggops.get(op)
+    n = keys.shape[0]
+    lane_shape = values.shape[1:]
+    cols = values.reshape(n, math.prod(lane_shape))
+    k_s, *v_cols = jax.lax.sort(
+        (keys, *(cols[:, i] for i in range(cols.shape[1]))), num_keys=1)
+    v_s = jnp.stack(v_cols, axis=1).reshape(values.shape)
+    change = _run_change(k_s)
+    real_start = change & (k_s != EMPTY_KEY)
+    seg = jax.lax.cummax(
+        jnp.where(change, jnp.arange(n, dtype=jnp.int32), 0), axis=0)
+    comb = aggop.segment_reduce(v_s, seg, num_segments=n,
+                                indices_are_sorted=True)
+    return k_s, real_start, comb
+
+
+def _run_starts(b_d, n_buckets):
+    """[n_buckets + 1] int32: the first position of each bucket's run in
+    the ascending bucket ids ``b_d`` (all in [0, n_buckets]); entry j is
+    the number of ids below j.  A sorted count and an exclusive prefix
+    sum: no binary-search loop on the TPU, and as fast as the search on
+    XLA:CPU (DESIGN.md §8)."""
+    counts = jax.ops.segment_sum(jnp.ones_like(b_d), b_d,
+                                 num_segments=n_buckets + 1,
+                                 indices_are_sorted=True)
+    return jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                            jnp.cumsum(counts)[:n_buckets]]).astype(jnp.int32)
 
 
 def _fpe_batched(
@@ -308,12 +367,10 @@ def _fpe_batched(
     nh = valid_d & ~is_hit  # distinct new keys joining the queue
 
     # per-bucket totals / per-key queue rank from prefix sums over the
-    # bucket-major layout (run starts found by a tiny n_buckets-query
-    # binary search — b_d is sorted)
+    # bucket-major layout (b_d is sorted, so each bucket is one run)
     sx = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                           jnp.cumsum(nh.astype(jnp.int32))])
-    rs = jnp.searchsorted(b_d, jnp.arange(n_buckets + 1, dtype=jnp.int32),
-                          method="scan").astype(jnp.int32)
+    rs = _run_starts(b_d, n_buckets)
     q_arr = sx[rs[1:]] - sx[rs[:-1]]  # [n_buckets] new keys per bucket
     j_d = sx[pos] - sx[rs[b_c]]  # rank of d among its bucket's new keys
     q_d = q_arr[b_c]
@@ -392,9 +449,9 @@ def sorted_combine(keys: jnp.ndarray, values: jnp.ndarray, *, op: str = "sum") -
     if n == 0:
         return CombineResult(keys.astype(jnp.int32), values,
                              jnp.zeros((), jnp.int32))
-    # One radix sort + searchsorted + unsorted segment reduce
-    # (_group_reduce) — values never ride a comparator sort, and no
-    # sentinel remap, so INT32_MAX stays a legal, distinct key.
+    # One key sort + group map + segment reduce (_group_reduce, whose map
+    # is split by platform), and no sentinel remap, so INT32_MAX stays a
+    # legal, distinct key.
     k_s, real_start, comb = _group_reduce(keys, values, op=op)
     pos = jnp.arange(n, dtype=jnp.int32)
     # k_s is ascending, so first positions sort to ascending-key order

@@ -63,3 +63,32 @@ def dict_aggregate(keys, values, op="sum"):
     }
     f = reducers[op]
     return {k: f(vs) for k, vs in groups.items()}
+
+
+def build_off_cpu(fn, **static):
+    """A fresh jit of a jitted ``core.kvagg`` entry point's body that runs,
+    here on the CPU, the group map kvagg lowers for a TPU
+    (``_group_reduce_sorted``) where ``lax.platform_dependent`` would pick
+    the CPU's search.
+
+    The map is swapped only while the returned function runs, so its
+    traces see it and no other trace does (the entry points' own jit
+    caches keep the CPU branch).
+    """
+    import functools
+
+    import jax
+
+    from repro.core import kvagg
+
+    jitted = jax.jit(functools.partial(fn.__wrapped__, **static))
+
+    def call(*args, **kwargs):
+        on_cpu = kvagg._group_reduce
+        kvagg._group_reduce = kvagg._group_reduce_sorted
+        try:
+            return jitted(*args, **kwargs)
+        finally:
+            kvagg._group_reduce = on_cpu
+
+    return call

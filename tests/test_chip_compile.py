@@ -66,6 +66,36 @@ def test_fpe_kernel_compiles_for_v5e(op, lanes, one_chip,
     assert out < 1.01 * 4 * (n + CAPACITY) * (1 + max(lanes, default=1))
 
 
+#: the group maps of the stream cell's shapes: a BPE over a 32,767-record
+#: batch's [n + capacity] eviction stream, and the fast-path FPE itself
+GROUP_MAP_PROGRAMS = {
+    "sorted_combine": (
+        lambda k, v: kvagg.sorted_combine(k, v, op="sum"),
+        ((32767 + CAPACITY,), jnp.int32), ((32767 + CAPACITY,), jnp.float32)),
+    "fpe_fast": (
+        lambda k, v, tk, tv: kvagg.fpe_aggregate(
+            k, v, capacity=CAPACITY, ways=WAYS, op="sum",
+            exact_stream=False, table_keys=tk, table_values=tv),
+        ((32767,), jnp.int32), ((32767,), jnp.float32),
+        ((CAPACITY,), jnp.int32), ((CAPACITY,), jnp.float32)),
+}
+
+
+@pytest.mark.parametrize("program", sorted(GROUP_MAP_PROGRAMS))
+def test_group_maps_compile_without_loops_for_v5e(program, one_chip,
+                                                  no_persistent_cache):
+    """Lowered for the TPU, each record finds its group from the sort: no
+    ``while`` (the CPU's binary searches) is left in the program, while the
+    same program compiled for the CPU keeps its search loops."""
+    fn, *shapes = GROUP_MAP_PROGRAMS[program]
+    tpu = jax.jit(fn).lower(*(jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                              for s, d in shapes)).compile()
+    assert "while(" not in tpu.as_text()
+    cpu = jax.jit(fn).lower(*(jax.ShapeDtypeStruct(s, d)
+                              for s, d in shapes)).compile()
+    assert "while(" in cpu.as_text()
+
+
 def test_fast_path_over_int32_limit_raises():
     """n * capacity at the int32 limit: a ValueError naming it, never a
     silent switch to the serial scan."""

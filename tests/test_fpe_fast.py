@@ -9,10 +9,13 @@ on, and asserts the shape-stable streaming ingest compiles O(1) traces.
 Hypothesis generalizations live in tests/test_fpe_fast_properties.py.
 """
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import build_off_cpu
 from repro.core import aggops, dataplane, kvagg
 from repro.core.dataplane import CascadePlan, LevelSpec
 
@@ -221,6 +224,61 @@ def test_sim_fast_path_delivers_same_totals():
                                 cfg=sim.NetConfig(exact_stream=False)))
     assert exact.delivered_table() == fast.delivered_table()
     assert fast.jct_s > 0
+
+
+@pytest.mark.parametrize("n_buckets,n,fill", [
+    (1, 1, 1.0), (7, 40, 0.5), (16, 300, 0.9), (64, 50, 0.0), (64, 33, 1.0),
+])
+def test_run_starts_match_search(n_buckets, n, fill):
+    """Bucket run starts: count + prefix sum == a binary search, over
+    sorted bucket ids in [0, n_buckets] (n_buckets marks a vacant slot)."""
+    r = np.random.default_rng(n_buckets * 31 + n)
+    b_d = np.sort(np.where(r.random(n) < fill,
+                           r.integers(0, n_buckets, size=n), n_buckets))
+    want = np.searchsorted(b_d, np.arange(n_buckets + 1), side="left")
+    got = kvagg._run_starts(jnp.asarray(b_d.astype(np.int32)), n_buckets)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(got, want)
+
+
+def _fast_calls(fpe, keys, carried, *, resumed):
+    """One fast-path call, or two that resume the first call's table."""
+    chunks = (np.array_split(np.arange(keys.shape[0]), 2) if resumed
+              else [np.arange(keys.shape[0])])
+    tk = tv = None
+    outs = []
+    for idx in chunks:
+        res = fpe(jnp.asarray(keys[idx]), jnp.asarray(carried[idx]),
+                  table_keys=tk, table_values=tv)
+        tk, tv = res.table_keys, res.table_values
+        outs.append(res)
+    return outs
+
+
+@pytest.mark.parametrize("resumed", [False, True])
+@pytest.mark.parametrize("op", ["sum", "max", "min", "mean"])
+def test_fast_path_branches_bit_identical(op, resumed):
+    """fpe_aggregate(exact_stream=False) as lowered for a TPU == as lowered
+    for the CPU: tables and eviction streams bit for bit, on a fresh and a
+    resumed table, with padding, negative keys and INT32_MAX."""
+    r = np.random.default_rng(17)
+    n = 300
+    keys = r.integers(-60, 60, size=n).astype(np.int32)
+    keys[r.random(n) < 0.25] = EMPTY
+    keys[::23] = np.iinfo(np.int32).max
+    raw = r.integers(-8, 8, size=n).astype(np.float32)
+    carried = np.asarray(aggops.get(op).prepare_values(jnp.asarray(raw)))
+    static = dict(capacity=32, ways=4, op=op, exact_stream=False)
+    want = _fast_calls(functools.partial(kvagg.fpe_aggregate, **static),
+                       keys, carried, resumed=resumed)
+    got = _fast_calls(build_off_cpu(kvagg.fpe_aggregate, **static),
+                      keys, carried, resumed=resumed)
+    assert any(int(jnp.sum(w.evict_keys != EMPTY)) for w in want)
+    for g, w in zip(got, want):
+        for field, gf, wf in zip(w._fields, g, w):
+            gf, wf = np.asarray(gf), np.asarray(wf)
+            assert (gf.shape, gf.dtype) == (wf.shape, wf.dtype), field
+            assert gf.tobytes() == wf.tobytes(), field
 
 
 def test_sorted_combine_int32max_key_legal():

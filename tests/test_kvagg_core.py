@@ -1,15 +1,19 @@
 """core.kvagg — the pure-jnp SwitchAgg node (FPE scan + BPE sorted combine)."""
 
+import importlib.util
+import pathlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 pytest.importorskip("hypothesis", reason="dev-only dep: pip install -r requirements-dev.txt")
 from hypothesis import given, settings, strategies as st
 
-from conftest import dict_aggregate
-from repro.core import kvagg
+from conftest import build_off_cpu, dict_aggregate
+from repro.core import aggops, kvagg
 
 EMPTY = int(kvagg.EMPTY_KEY)
+IMAX = int(np.iinfo(np.int32).max)
 
 
 # --------------------------------------------------------------------------
@@ -61,6 +65,91 @@ def test_sorted_combine_ops(op, rng):
     assert got.keys() == want.keys()
     for k in want:
         np.testing.assert_allclose(got[k], want[k], rtol=1e-6)
+
+
+def _edge_stream(case):
+    """(keys, integer-valued raw values) for the group-map equivalence
+    cases: EMPTY padding, negative keys and INT32_MAX ("mixed"), n = 0,
+    n = 1 and a stream of padding only."""
+    r = np.random.default_rng(11)
+    if case == "n0":
+        keys = np.zeros((0,), np.int32)
+    elif case == "n1":
+        keys = np.asarray([IMAX], np.int32)
+    elif case == "all_empty":
+        keys = np.full((33,), EMPTY, np.int32)
+    else:
+        keys = r.integers(-40, 40, size=257).astype(np.int32)
+        keys[r.random(257) < 0.3] = EMPTY
+        keys[::17] = IMAX
+    raw = r.integers(-8, 8, size=keys.shape[0]).astype(np.float32)
+    return jnp.asarray(keys), raw
+
+
+def assert_bit_identical(got, want):
+    """Every field of two results: same shape, dtype and bytes."""
+    for i, (g, w) in enumerate(zip(got, want)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert (g.shape, g.dtype) == (w.shape, w.dtype), f"field {i}"
+        assert g.tobytes() == w.tobytes(), f"field {i}: {g} != {w}"
+
+
+EDGE_CASES = ["mixed", "n0", "n1", "all_empty"]
+BRANCH_OPS = ["sum", "max", "min", "mean", "count", "logsumexp"]
+
+
+def assert_same_totals(op, got, want):
+    """Bit for bit, except logsumexp, whose exp and log may round apart
+    when the branches add in another order."""
+    if op == "logsumexp":
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6)
+    else:
+        assert_bit_identical([got], [want])
+
+
+@pytest.mark.parametrize("case", EDGE_CASES)
+@pytest.mark.parametrize("op", BRANCH_OPS)
+def test_group_reduce_branches_agree(op, case):
+    """The TPU group map (one sort carrying the values, run starts by a
+    running max) gives the CPU search's sorted keys, first-occurrence mask
+    and, at every first occurrence, the same combined value."""
+    keys, raw = _edge_stream(case)
+    carried = aggops.get(op).prepare_values(jnp.asarray(raw))
+    ks_s, first_s, comb_s = kvagg._group_reduce_searched(keys, carried, op=op)
+    ks_c, first_c, comb_c = kvagg._group_reduce_sorted(keys, carried, op=op)
+    assert_bit_identical((ks_c, first_c), (ks_s, first_s))
+    first = np.asarray(first_s)
+    assert_same_totals(op, np.asarray(comb_c)[first],
+                       np.asarray(comb_s)[first])
+
+
+@pytest.mark.parametrize("case", EDGE_CASES)
+@pytest.mark.parametrize("op", BRANCH_OPS)
+def test_sorted_combine_branches_bit_identical(op, case):
+    """sorted_combine as lowered for a TPU == as lowered for the CPU, bit
+    for bit, on integer-valued inputs (logsumexp's totals to rounding)."""
+    keys, raw = _edge_stream(case)
+    carried = aggops.get(op).prepare_values(jnp.asarray(raw))
+    want = kvagg.sorted_combine(keys, carried, op=op)
+    got = build_off_cpu(kvagg.sorted_combine, op=op)(keys, carried)
+    assert_bit_identical((got.unique_keys, got.n_unique),
+                         (want.unique_keys, want.n_unique))
+    assert_same_totals(op, got.combined_values, want.combined_values)
+
+
+def test_group_map_bench_times_both_branches():
+    """benchmarks/bench_group_map.py, the split's reason as a script: one
+    row per branch, the branches' groups checked equal inside."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_group_map", pathlib.Path(__file__).resolve().parents[1]
+        / "benchmarks" / "bench_group_map.py")
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    rows = bench.main(["--sizes", "1000,1", "--reps", "1"])
+    assert [(r["n"], r["branch"]) for r in rows] == [
+        (1000, "searched"), (1000, "sorted"), (1, "searched"), (1, "sorted")]
+    assert all(r["ms"] > 0 for r in rows)
 
 
 # --------------------------------------------------------------------------
